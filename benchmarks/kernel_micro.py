@@ -114,16 +114,13 @@ def sparsity_rows(rng) -> List[Row]:
         b_ukcn = F.dense_to_ell(b, 0, cap(b, 0, s))
         cases = [
             ("spmm", D.SPMM, a, b_unck,
-             lambda **kw: ops.spmm(a, b_unck, interpret=True, **kw)),
+             lambda **kw: ops.spmm(a, b_unck, **kw)),
             ("spgemm_inner", D.SPGEMM_INNER, a_umck, b_unck,
-             lambda **kw: ops.spgemm_inner(a_umck, b_unck, interpret=True,
-                                           **kw)),
+             lambda **kw: ops.spgemm_inner(a_umck, b_unck, **kw)),
             ("spgemm_outer", D.SPGEMM_OUTER, a_ukcm, b_ukcn,
-             lambda **kw: ops.spgemm_outer(a_ukcm, b_ukcn, interpret=True,
-                                           **kw)),
+             lambda **kw: ops.spgemm_outer(a_ukcm, b_ukcn, **kw)),
             ("spgemm_gustavson", D.SPGEMM_GUSTAVSON, a_ukcm, b_unck,
-             lambda **kw: ops.spgemm_gustavson(a_ukcm, b_unck,
-                                               interpret=True, **kw)),
+             lambda **kw: ops.spgemm_gustavson(a_ukcm, b_unck, **kw)),
         ]
         for name, cls, opa, opb, run in cases:
             # Baseline = the old expansion path as shipped (128 blocks).
@@ -150,7 +147,9 @@ def sparsity_rows(rng) -> List[Row]:
             ))
             if name in CLAIM_KERNELS and dens == CLAIM_DENSITY:
                 claim_ratios[name] = ratio
-    for name in CLAIM_KERNELS:
+    # The claim is about the interpreter's bodies: under Mosaic ``auto`` is
+    # the expansion body itself, so the ratio is 1 by construction.
+    for name in CLAIM_KERNELS if ops.default_interpret() else ():
         assert claim_ratios[name] <= CLAIM_MAX_RATIO, (
             f"perf claim tripwire: {name} at density {CLAIM_DENSITY} ran at "
             f"{claim_ratios[name]:.2f}x the expansion body "
@@ -305,18 +304,18 @@ def run() -> List[Row]:
     b_ukcn = F.dense_to_ell(b, 0, F.required_capacity(b, 0))
 
     cases = [
-        ("gemm", a, b, lambda: ops.gemm(a, b, interpret=True),
+        ("gemm", a, b, lambda: ops.gemm(a, b),
          lambda: ref.gemm_ref(a, b), D.GEMM),
-        ("spmm", a, b_unck, lambda: ops.spmm(a, b_unck, interpret=True),
+        ("spmm", a, b_unck, lambda: ops.spmm(a, b_unck),
          lambda: ref.spmm_ref(a, b_unck), D.SPMM),
         ("spgemm_inner", a_umck, b_unck,
-         lambda: ops.spgemm_inner(a_umck, b_unck, interpret=True),
+         lambda: ops.spgemm_inner(a_umck, b_unck),
          lambda: ref.spgemm_inner_ref(a_umck, b_unck), D.SPGEMM_INNER),
         ("spgemm_outer", a_ukcm, b_ukcn,
-         lambda: ops.spgemm_outer(a_ukcm, b_ukcn, interpret=True),
+         lambda: ops.spgemm_outer(a_ukcm, b_ukcn),
          lambda: ref.spgemm_outer_ref(a_ukcm, b_ukcn), D.SPGEMM_OUTER),
         ("spgemm_gustavson", a_ukcm, b_unck,
-         lambda: ops.spgemm_gustavson(a_ukcm, b_unck, interpret=True),
+         lambda: ops.spgemm_gustavson(a_ukcm, b_unck),
          lambda: ref.spgemm_gustavson_ref(a_ukcm, b_unck), D.SPGEMM_GUSTAVSON),
     ]
     rows: List[Row] = []

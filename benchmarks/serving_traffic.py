@@ -119,8 +119,12 @@ def sustained_throughput_row() -> Row:
     src = _SUSTAINED_CHILD.replace("__SRC__", repr(_SRC)).replace(
         "__PARAMS__", repr((SUSTAINED_SCALE, SUSTAINED_DEPTH,
                             GAP_FACTOR, DEADLINE_SLACK)))
+    # The child runs on 8 forced host devices by construction; on a TPU
+    # host the parent may already hold the chip, which the child could
+    # not open.
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", src], capture_output=True,
-                         text=True, timeout=1800)
+                         text=True, timeout=1800, env=env)
     if out.returncode != 0:
         raise RuntimeError(
             f"sustained-throughput child failed:\n{out.stderr[-3000:]}")

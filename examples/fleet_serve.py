@@ -59,13 +59,13 @@ def main() -> None:
 
     # -- single server as the ground truth ------------------------------
     single = ClusterServer(cfg, policy="affinity").run_trace(
-        trace, interpret=True, block=64)
+        trace, block=64)
 
     # -- 3-replica fleet, one replica killed mid-batch ------------------
     fleet = FleetServer(cfg, n_replicas=3, policy="affinity",
                         fault_plan=FaultPlan.kill_mid_batch(0, batch=0),
                         failover_detect_cycles=1000.0)
-    fr = fleet.run_trace(trace, interpret=True, block=64)
+    fr = fleet.run_trace(trace, block=64)
 
     print(f"fleet: {fr.report.n_replicas_live}/"
           f"{fr.report.n_replicas_launched} replicas live, "

@@ -40,7 +40,7 @@ def main() -> None:
     # Combine == EIE-like SpMM of R (sparse) with expert outputs (dense).
     summaries = jax.random.normal(jax.random.PRNGKey(2),
                                   (cfg.n_experts, cfg.d_model))
-    via_spmm = ops.spmm_mirror(ell, summaries, bm=32, bn=64, interpret=True)
+    via_spmm = ops.spmm_mirror(ell, summaries, bm=32, bn=64)
     dense_r = np.zeros(ell.shape, np.float32)
     for ti in range(t):
         for j in range(cfg.experts_per_token):

@@ -21,10 +21,12 @@ Perfetto-loadable Chrome trace (DESIGN.md §8).
 import argparse
 import dataclasses
 import math
+import pathlib
 import tempfile
 
 import numpy as np
 
+from repro.common.compile_cache import use_compile_cache
 from repro.core import dse
 from repro.core.scheduler import available_policies, schedule_many_kernels
 from repro.serve.cluster import (
@@ -60,6 +62,8 @@ def main() -> None:
                     help="export the served timeline as a Chrome trace "
                          "JSON (open in https://ui.perfetto.dev)")
     args = ap.parse_args()
+    use_compile_cache(pathlib.Path(__file__).resolve().parents[1]
+                      / ".jax_cache")
 
     print("searching the serving design (AESPA-opt, memoized)...")
     config = dse.aespa_opt()

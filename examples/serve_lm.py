@@ -4,12 +4,14 @@ assigned architecture (reduced config so it runs on CPU in seconds).
     PYTHONPATH=src python examples/serve_lm.py --arch gemma3-1b --requests 4
 """
 import argparse
+import pathlib
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import use_compile_cache
 from repro.configs import all_archs, get_reduced
 from repro.models import build
 from repro.serve.engine import greedy_generate
@@ -22,6 +24,8 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=8)
     ap.add_argument("--gen-len", type=int, default=24)
     args = ap.parse_args()
+    use_compile_cache(pathlib.Path(__file__).resolve().parents[1]
+                      / ".jax_cache")
 
     cfg = get_reduced(args.arch)
     model = build(cfg)

@@ -90,7 +90,7 @@ from repro.core.hetero_matmul import (
 )
 from repro.core.scheduler import KernelSchedule
 from repro.formats.ell import bucket_capacity
-from repro.launch.mesh import axis_sizes, set_mesh, shard_map
+from repro.launch.mesh import axis_sizes
 from repro.obs import trace as _trace_mod
 
 import contextlib
@@ -339,8 +339,8 @@ def _build_program(mesh, axis, per_device, out_shapes, operand_struct,
         n_jobs = len(out_shapes)
         in_spec = ([P()] * n_jobs, [P()] * n_jobs)
         out_spec = tuple(P() for _ in range(n_jobs))
-        return jax.jit(shard_map(spmd, mesh, in_specs=in_spec,
-                                 out_specs=out_spec))
+        return jax.jit(jax.shard_map(spmd, mesh=mesh, in_specs=in_spec,
+                                     out_specs=out_spec, check_vma=False))
 
     return _cached_program(key, build)
 
@@ -401,7 +401,8 @@ def _build_packed_program(mesh, axis, meta, out_shapes, payload_struct,
         else:
             out_specs = tuple(P() for _ in out_shapes)
         return jax.jit(
-            shard_map(spmd, mesh, in_specs=in_specs, out_specs=out_specs),
+            jax.shard_map(spmd, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False),
             donate_argnums=tuple(range(n_payloads)))
 
     return _cached_program(key, build)
@@ -539,7 +540,7 @@ def _dispatch_batch(batch_id, jobs, config, mesh, axis, interpret, block,
         dev_payloads = tuple(jax.device_put(buf, sharding)
                              for buf in payloads)
         dispatch_s = time.perf_counter() - origin
-        with mesh, set_mesh(mesh), _quiet_donation():
+        with jax.set_mesh(mesh), _quiet_donation():
             if measure:
                 partials, token = fn(*dev_payloads)
                 return _InFlight(batch_id, len(jobs), None, partials,
@@ -581,7 +582,7 @@ def _dispatch_batch(batch_id, jobs, config, mesh, axis, interpret, block,
               for a, b in zip(a_ops, b_ops)),
         interpret, block)
     dispatch_s = time.perf_counter() - origin
-    with mesh, set_mesh(mesh):
+    with jax.set_mesh(mesh):
         outs = fn(a_ops, b_ops)
     return _InFlight(batch_id, len(jobs), list(outs), None, None, spans,
                      dispatch_s)

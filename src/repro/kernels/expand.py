@@ -32,11 +32,23 @@ lowerings are bit-identical: coordinates are unique within a fiber, so
 every output element receives at most one contribution, and padded ids
 (``PAD_ID``) never match the window — the "invalid computation never
 scheduled" property of the index-match hardware being modelled.
+
+These lowerings serve the per-tile expansion bodies, which only the
+interpreter runs. Under Mosaic the expansion body is
+:func:`expansion_gemm`: each compressed operand is expanded once into a
+dense HBM operand by the :func:`expand_table_pallas` kernel, then
+contracted by the gemm kernel (DESIGN.md §7).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+from repro.formats.ell import PAD_ID, EllMatrix, pad_capacity
+from repro.kernels.gemm import gemm_pallas
 
 #: Max one-hot contraction depth per dot_general (method="dot"). Bounds the
 #: 3-D mask to (fibers × DEFAULT_CHUNK × width) elements of VMEM.
@@ -137,3 +149,112 @@ def expand_major(ids, vals, base, height: int, out_dtype=jnp.float32,
     layout — fibers become columns (the SpMM weight-tile orientation)."""
     return expand_minor(ids, vals, base, height, out_dtype,
                         chunk=chunk, method=method).T
+
+
+# ------------------------------------------------ Mosaic expansion body
+#: Tiles of :func:`expand_table_pallas`: output ``(EXPAND_BLOCK, 128)``
+#: (minor coordinates × fibers), capacity slots per grid step.
+EXPAND_BLOCK = 128
+EXPAND_FIBERS = 128
+EXPAND_SLOTS = 256
+
+
+def _expand_table_kernel(ids_ref, vals_ref, o_ref, *, bw: int, cc: int):
+    w, c = pl.program_id(0), pl.program_id(2)
+
+    @pl.when(c == 0)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    # Live ids ascend within a fiber from slot 0, so slot s holds an id
+    # >= s: capacity chunks starting past this window's end are empty here.
+    @pl.when(c * cc < (w + 1) * bw)
+    def _accumulate():
+        rows = w * bw + jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 0)
+
+        def group(g, acc):
+            s0 = pl.multiple_of(g * 8, 8)
+            ids8 = ids_ref[pl.ds(s0, 8), :]
+            vals8 = vals_ref[pl.ds(s0, 8), :].astype(jnp.float32)
+            for r in range(8):   # one capacity slot per (bw, bf) select
+                acc = acc + jnp.where(rows == ids8[r:r + 1, :],
+                                      vals8[r:r + 1, :], 0.0)
+            return acc
+
+        o_ref[...] += jax.lax.fori_loop(
+            0, cc // 8, group, jnp.zeros(o_ref.shape, jnp.float32))
+
+
+def expand_table_pallas(e, *, rows: int, cols: int,
+                        interpret: bool = False) -> jnp.ndarray:
+    """Expand ``e``'s fibers into the f32 table ``(rows, cols)``: entry
+    ``[id, f]`` holds fiber ``f``'s value at minor coordinate ``id``, and
+    the padding beyond ``e.minor_size`` × ``e.n_fibers`` is zero. For column
+    fibers that table is the dense matrix, for row fibers its transpose.
+
+    The Mosaic lowering of the expansion bodies (DESIGN.md §7): no scatter
+    and no gather — every capacity slot is one vector compare-and-select
+    over a ``(EXPAND_BLOCK, 128)`` output tile, accumulated in VMEM over a
+    capacity grid axis. ``rows`` and ``cols`` must be multiples of
+    :data:`EXPAND_BLOCK` and :data:`EXPAND_FIBERS`.
+    """
+    bw, bf = EXPAND_BLOCK, EXPAND_FIBERS
+    nf = e.n_fibers
+    assert rows % bw == 0 and cols % bf == 0, (rows, cols)
+    assert rows >= e.minor_size and cols >= nf, (rows, cols, e.shape)
+    cc = min(EXPAND_SLOTS, pl.cdiv(e.cap, 8) * 8)
+    e = pad_capacity(e, pl.cdiv(e.cap, cc) * cc)
+    # Capacity-major layout: one slot across 128 fibers is one lane row.
+    ids = jnp.pad(e.ids.T, ((0, 0), (0, cols - nf)), constant_values=PAD_ID)
+    vals = jnp.pad(e.vals.T, ((0, 0), (0, cols - nf)))
+    kernel = functools.partial(_expand_table_kernel, bw=bw, cc=cc)
+    return pl.pallas_call(
+        kernel,
+        grid=(rows // bw, cols // bf, e.cap // cc),
+        in_specs=[
+            pl.BlockSpec((cc, bf), lambda w, f, c: (c, f)),
+            pl.BlockSpec((cc, bf), lambda w, f, c: (c, f)),
+        ],
+        out_specs=pl.BlockSpec((bw, bf), lambda w, f, c: (w, f)),
+        out_shape=jax.ShapeDtypeStruct((rows, cols), jnp.float32),
+        interpret=interpret,
+    )(ids, vals)
+
+
+def _dense_operand(x, rows: int, cols: int, interpret: bool):
+    """``x`` (dense or :class:`EllMatrix`) as a zero-padded dense
+    ``(rows, cols)`` array in its own dtype."""
+    if not isinstance(x, EllMatrix):
+        return jnp.pad(x, ((0, rows - x.shape[0]), (0, cols - x.shape[1])))
+    if x.major_axis == 1:
+        t = expand_table_pallas(x, rows=rows, cols=cols, interpret=interpret)
+    else:
+        t = expand_table_pallas(x, rows=cols, cols=rows,
+                                interpret=interpret).T
+    return t.astype(x.vals.dtype)
+
+
+def expansion_gemm(a, b, *, bm: int, bn: int, bk: int = EXPAND_BLOCK,
+                   interpret: bool = False) -> jnp.ndarray:
+    """``a @ b`` for any mix of dense and compressed operands through the
+    expansion body as Mosaic runs it: each compressed operand is expanded
+    ONCE by :func:`expand_table_pallas`, then the output-stationary
+    :func:`repro.kernels.gemm.gemm_pallas` contracts the two on the MXU.
+
+    This is the body ``method="auto"`` takes on the TPU for every sparse
+    class. The per-tile expansion bodies of the class kernels re-expand an
+    operand for every output tile and hold whole fibers in VMEM, which the
+    published Table-I sizes overflow; here VMEM holds fixed tiles and the
+    expansion cost is paid once per call.
+    """
+    m, k = a.shape
+    n = b.shape[1]
+    mp, kp, np_ = (pl.cdiv(d, EXPAND_BLOCK) * EXPAND_BLOCK
+                   for d in (m, k, n))
+    ad = _dense_operand(a, mp, kp, interpret)
+    bd = _dense_operand(b, kp, np_, interpret)
+    # Padded dims are multiples of EXPAND_BLOCK: blocks below it would only
+    # multiply grid steps (and break Mosaic's 128-lane tiling).
+    bm, bn, bk = (max(x, EXPAND_BLOCK) for x in (bm, bn, bk))
+    out = gemm_pallas(ad, bd, bm=bm, bn=bn, bk=bk, interpret=interpret)
+    return out[:m, :n]

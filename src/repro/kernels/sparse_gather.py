@@ -49,6 +49,17 @@ def fit_block(dim: int, block: int) -> int:
     return math.gcd(dim, block)
 
 
+def check_sparse_lowers(interpret: bool, kernel: str, primitive: str):
+    """The sparse bodies run only under the Pallas interpreter: Mosaic
+    (the TPU compiler) refuses ``primitive`` inside a kernel. Raise before
+    lowering rather than fail inside it."""
+    if not interpret:
+        raise ValueError(
+            f"{kernel}: method='sparse' uses {primitive} inside the kernel, "
+            "which Mosaic does not lower; on the TPU use method='auto' or "
+            "'reference' (the expansion body)")
+
+
 def scatter_table(ids, vals, height: int):
     """Fibers -> transposed dense table ``(height, n_fibers)``.
 

@@ -3,23 +3,24 @@
 
 Two bodies (DESIGN.md §7):
 
-``method="sparse"`` (default) — the sparsity-proportional body. The grid
-walks M blocks outermost; at the first N step of each M block the kernel
-scatter-constructs A's windowed dense ``(K, bm)`` table (only coordinates
-inside the M window land; cost ∝ A's in-window nonzeros) into persistent
-VMEM scratch and amortizes it across every N block. B's column fibers then
-*drive* the contraction exactly as in MatRaptor: each nonzero ``B[k, n]``
-names table row ``k``; the kernel gathers those rows in capacity chunks
-and batch-dots them against ``b.vals``, accumulating in register across
-the fiber dimension — per-column work ∝ that column's nonzeros. Trip
-counts come from the scalar-prefetched live-chunk bounds
+``method="sparse"`` (the interpreter's default) — the sparsity-proportional
+body. The grid walks M blocks outermost; at the first N step of each M
+block the kernel scatter-constructs A's windowed dense ``(K, bm)`` table
+(only coordinates inside the M window land; cost ∝ A's in-window nonzeros)
+into persistent VMEM scratch and amortizes it across every N block. B's
+column fibers then *drive* the contraction exactly as in MatRaptor: each
+nonzero ``B[k, n]`` names table row ``k``; the kernel gathers those rows in
+capacity chunks and batch-dots them against ``b.vals``, accumulating in
+register across the fiber dimension — per-column work ∝ that column's
+nonzeros. Trip counts come from the scalar-prefetched live-chunk bounds
 (:func:`repro.formats.ell.block_chunk_counts`); M windows that
-:func:`~repro.formats.ell.block_window_nnz` proves empty of A nonzeros
-skip construction and every tile that would read them.
+:func:`~repro.formats.ell.block_window_nnz` proves empty of A nonzeros skip
+construction and every tile that would read them.
 
-``method="reference"`` — the PR-1 body, kept as the parity oracle: both
-operands one-hot expanded to dense (bn, bk)/(bk, bm) tiles per
-(N, M, K-block) step, contracted on the MXU.
+``method="reference"`` — under Mosaic (the default there)
+:func:`repro.kernels.expand.expansion_gemm`; under the interpreter the PR-1
+body, kept as the parity oracle: both operands one-hot expanded to dense
+(bn, bk)/(bk, bm) tiles per (N, M, K-block) step, contracted on the MXU.
 """
 from __future__ import annotations
 
@@ -36,8 +37,12 @@ from repro.formats.ell import (
     block_window_nnz,
     pad_capacity,
 )
-from repro.kernels.expand import expand_minor
-from repro.kernels.sparse_gather import chunked_gather_contract, fit_block
+from repro.kernels.expand import expand_minor, expansion_gemm
+from repro.kernels.sparse_gather import (
+    check_sparse_lowers,
+    chunked_gather_contract,
+    fit_block,
+)
 
 #: Capacity-chunk width of the gather contraction over B's column fibers.
 GUSTAVSON_FIBER_CHUNK = 16
@@ -46,7 +51,7 @@ GUSTAVSON_FIBER_CHUNK = 16
 # ------------------------------------------------------------ reference body
 def _gustavson_reference_kernel(
     av_ref, ai_ref, bv_ref, bi_ref, o_ref, acc_ref,
-    *, bm: int, bk: int, k_steps: int, method: str,
+    *, bm: int, bk: int, k_steps: int,
 ):
     j, i, kk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
@@ -58,10 +63,10 @@ def _gustavson_reference_kernel(
     # B column fibers (bn, cap_b) -> dense (bn, bk) for this K block: the
     # entries "scheduled" from the stream into the MAC queue.
     sb = expand_minor(bi_ref[...], bv_ref[...], k0, bk, jnp.float32,
-                      method=method)   # (bn, bk)
+                      method="gather")   # (bn, bk)
     # A K-major column fibers (bk, cap_a) -> dense (bk, bm) over the M block.
     ea = expand_minor(ai_ref[...], av_ref[...], i * bm, bm, jnp.float32,
-                      method=method)  # (bk, bm)
+                      method="gather")  # (bk, bm)
     # O[mblock, nblock] += ea(k,m)ᵀ·sb(n,k)ᵀ, contracted over k.
     acc_ref[...] += jax.lax.dot_general(
         ea, sb, (((0,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -79,8 +84,7 @@ def _gustavson_reference(a, b, *, bm, bn, bk, interpret):
     out_dtype = jnp.result_type(a.vals.dtype, b.vals.dtype)
 
     kernel = functools.partial(_gustavson_reference_kernel, bm=bm, bk=bk,
-                               k_steps=k_steps,
-                               method="gather" if interpret else "dot")
+                               k_steps=k_steps)
     return pl.pallas_call(
         kernel,
         grid=(n // bn, m // bm, k_steps),  # N outermost: column-wise walk
@@ -168,10 +172,12 @@ def spgemm_gustavson_pallas(
     """A (K column-fibers, ids->M) × B (N column-fibers, ids->K) -> (M, N).
 
     ``method``: ``"sparse"`` (B-driven gather contraction, per-column work
-    ∝ B's nonzeros), ``"reference"`` (PR-1 expansion oracle), or ``"auto"``
-    — sparse while the gather volume (∝ ``cap_b``) undercuts the dense-K
-    expansion it replaces (``cap_b <= K/4``). Blocks auto-shrink to divide
-    ragged shapes (``bk`` only tiles the reference body).
+    ∝ B's nonzeros; interpreter only), ``"reference"`` (expansion body), or
+    ``"auto"`` — under the interpreter sparse while the gather volume
+    (∝ ``cap_b``) undercuts the dense-K expansion it replaces
+    (``cap_b <= K/4``); under Mosaic always the expansion body, lowered as
+    :func:`~repro.kernels.expand.expansion_gemm`. Blocks auto-shrink to
+    divide ragged shapes (``bk`` only tiles the reference body).
     """
     assert a.major_axis == 1 and b.major_axis == 1
     m, k = a.shape
@@ -180,11 +186,15 @@ def spgemm_gustavson_pallas(
     bm = fit_block(m, bm)
     bn = fit_block(n, bn)
     if method == "auto":
-        method = "sparse" if 4 * b.cap <= k else "reference"
+        method = "sparse" if interpret and 4 * b.cap <= k else "reference"
     if method == "reference":
+        if not interpret:
+            return expansion_gemm(a, b, bm=bm, bn=bn, bk=bk)
         return _gustavson_reference(a, b, bm=bm, bn=bn, bk=fit_block(k, bk),
                                     interpret=interpret)
     if method == "sparse":
+        check_sparse_lowers(interpret, "spgemm_gustavson",
+                            "an in-kernel gather")
         fc = min(GUSTAVSON_FIBER_CHUNK, b.cap)
         return _gustavson_sparse(a, b, bm=bm, bn=bn, fc=fc,
                                  interpret=interpret)

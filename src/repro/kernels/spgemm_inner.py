@@ -3,24 +3,25 @@ paper Fig 2c / Fig 3c.
 
 Two bodies (DESIGN.md §7):
 
-``method="sparse"`` (default) — the sparsity-proportional body. The grid
-runs N blocks outermost; at the first M step of each N block the kernel
-scatter-constructs B's dense ``(K, bn)`` column table once into persistent
-VMEM scratch (cost ∝ B's nonzeros) and amortizes it over every M block.
-The contraction never touches dense K: A's compressed row fibers are
+``method="sparse"`` (the interpreter's default) — the sparsity-proportional
+body. The grid runs N blocks outermost; at the first M step of each N block
+the kernel scatter-constructs B's dense ``(K, bn)`` column table once into
+persistent VMEM scratch (cost ∝ B's nonzeros) and amortizes it over every M
+block. The contraction never touches dense K: A's compressed row fibers are
 processed in capacity chunks — gather the table rows named by ``a.ids``,
 batch-dot against ``a.vals`` over the chunk, accumulate **in register**
-(the ``fori_loop`` carry) across the fiber dimension. The trip count is
-the scalar-prefetched live-chunk bound
+(the ``fori_loop`` carry) across the fiber dimension. The trip count is the
+scalar-prefetched live-chunk bound
 (:func:`repro.formats.ell.block_chunk_counts`), so contraction FLOPs and
 gather volume scale with A's nonzeros — ExTensor's intersection where the
 short operand's coordinates *drive* the walk. Blocks either operand proves
 empty skip construction/compute and write zeros.
 
-``method="reference"`` — the PR-1 body, kept as the parity oracle: one-hot
-expansion of BOTH operands' fibers to dense (bm, bk)/(bn, bk) tiles per
-(M, N, K) step, with the scalar-prefetch occupancy skip (hierarchical
-intersection) it introduced.
+``method="reference"`` — under Mosaic (the default there)
+:func:`repro.kernels.expand.expansion_gemm`; under the interpreter the PR-1
+body, kept as the parity oracle: one-hot expansion of BOTH operands' fibers
+to dense (bm, bk)/(bn, bk) tiles per (M, N, K) step, with the
+scalar-prefetch occupancy skip (hierarchical intersection) it introduced.
 """
 from __future__ import annotations
 
@@ -37,8 +38,12 @@ from repro.formats.ell import (
     pad_capacity,
     tile_occupancy,
 )
-from repro.kernels.expand import expand_minor
-from repro.kernels.sparse_gather import chunked_gather_contract, fit_block
+from repro.kernels.expand import expand_minor, expansion_gemm
+from repro.kernels.sparse_gather import (
+    check_sparse_lowers,
+    chunked_gather_contract,
+    fit_block,
+)
 
 #: Capacity-chunk width of the gather contraction (finer = tighter skipping,
 #: more loop iterations; 16 balances the two in interpret mode).
@@ -50,7 +55,7 @@ def _inner_reference_kernel(
     a_occ_ref, b_occ_ref,           # scalar-prefetch occupancy (SMEM)
     av_ref, ai_ref, bv_ref, bi_ref, # VMEM operand blocks
     o_ref, acc_ref,
-    *, bk: int, k_steps: int, method: str,
+    *, bk: int, k_steps: int,
 ):
     i, j, kk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
@@ -64,9 +69,9 @@ def _inner_reference_kernel(
     def _compute():
         k0 = kk * bk
         ea = expand_minor(ai_ref[...], av_ref[...], k0, bk, jnp.float32,
-                          method=method)  # (bm, bk)
+                          method="gather")  # (bm, bk)
         eb = expand_minor(bi_ref[...], bv_ref[...], k0, bk, jnp.float32,
-                          method=method)  # (bn, bk)
+                          method="gather")  # (bn, bk)
         acc_ref[...] += jax.lax.dot_general(
             ea, eb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -88,8 +93,7 @@ def _inner_reference(a, b, *, bm, bn, bk, interpret):
     b_occ = tile_occupancy(b, bk).reshape(n // bn, bn, k_steps).sum(1)
 
     kernel = functools.partial(_inner_reference_kernel, bk=bk,
-                               k_steps=k_steps,
-                               method="gather" if interpret else "dot")
+                               k_steps=k_steps)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(m // bm, n // bn, k_steps),
@@ -180,11 +184,13 @@ def spgemm_inner_pallas(
 ) -> jnp.ndarray:
     """A (M row-fibers, ids->K) × B (N column-fibers, ids->K) -> (M, N).
 
-    ``method``: ``"sparse"`` (gather contraction, FLOPs ∝ A's nonzeros),
-    ``"reference"`` (PR-1 expansion oracle), or ``"auto"`` — sparse while
-    the gather volume (∝ ``cap_a``) undercuts the dense-K expansion it
-    replaces (``cap_a <= K/4``). Blocks auto-shrink to divide ragged
-    shapes (``bk`` only tiles the reference body).
+    ``method``: ``"sparse"`` (gather contraction, FLOPs ∝ A's nonzeros;
+    interpreter only), ``"reference"`` (expansion body), or ``"auto"`` —
+    under the interpreter sparse while the gather volume (∝ ``cap_a``)
+    undercuts the dense-K expansion it replaces (``cap_a <= K/4``); under
+    Mosaic always the expansion body, lowered as
+    :func:`~repro.kernels.expand.expansion_gemm`. Blocks auto-shrink to
+    divide ragged shapes (``bk`` only tiles the reference body).
     """
     assert a.major_axis == 0 and b.major_axis == 1
     m, k = a.shape
@@ -193,11 +199,14 @@ def spgemm_inner_pallas(
     bm = fit_block(m, bm)
     bn = fit_block(n, bn)
     if method == "auto":
-        method = "sparse" if 4 * a.cap <= k else "reference"
+        method = "sparse" if interpret and 4 * a.cap <= k else "reference"
     if method == "reference":
+        if not interpret:
+            return expansion_gemm(a, b, bm=bm, bn=bn, bk=bk)
         return _inner_reference(a, b, bm=bm, bn=bn, bk=fit_block(k, bk),
                                 interpret=interpret)
     if method == "sparse":
+        check_sparse_lowers(interpret, "spgemm_inner", "an in-kernel gather")
         fc = min(INNER_FIBER_CHUNK, a.cap)
         return _inner_sparse(a, b, bm=bm, bn=bn, fc=fc, interpret=interpret)
     raise ValueError(f"unknown spgemm_inner method: {method!r}")

@@ -3,25 +3,26 @@ paper Fig 2d / Fig 3d.
 
 Two bodies (DESIGN.md §7):
 
-``method="sparse"`` (default while the tables fit VMEM) — the
-sparsity-proportional body. Both operands are K-major compressed fibers, so
-the whole matrices scatter into resident dense tables — A into ``(M, K)``,
-B into ``(N, K)`` VMEM scratch (coordinate-major, the fastest scatter
-layout) — ONCE at the first grid step (cost ∝ the two nonzero counts; this
-is the "linked-list merge" of OuterSPACE collapsed into a single scatter
-because the accumulator is dense). Every output tile
-is then one MXU dot contracting K between table row slices: no
-expansion, no K grid dimension, no per-step accumulator traffic. Per-tile
-``pl.when`` skips (driven by the scalar-prefetched per-window nonzero
-counts from :func:`repro.formats.ell.block_window_nnz`) write zeros for
-tiles whose M or N window holds no nonzeros. The resident tables bound the
-method: ``spgemm_outer_pallas`` auto-falls back to the reference body when
+``method="sparse"`` (the interpreter's default while the tables fit VMEM) —
+the sparsity-proportional body. Both operands are K-major compressed
+fibers, so the whole matrices scatter into resident dense tables — A into
+``(M, K)``, B into ``(N, K)`` VMEM scratch (coordinate-major, the fastest
+scatter layout) — ONCE at the first grid step (cost ∝ the two nonzero
+counts; this is the "linked-list merge" of OuterSPACE collapsed into a
+single scatter because the accumulator is dense). Every output tile is then
+one MXU dot contracting K between table row slices: no expansion, no K grid
+dimension, no per-step accumulator traffic. Per-tile ``pl.when`` skips
+(driven by the scalar-prefetched per-window nonzero counts from
+:func:`repro.formats.ell.block_window_nnz`) write zeros for tiles whose M
+or N window holds no nonzeros. The resident tables bound the method:
+``spgemm_outer_pallas`` auto-falls back to the reference body when
 ``4·K·(M+N)`` bytes exceed :data:`OUTER_TABLE_BYTES_MAX`.
 
-``method="reference"`` — the PR-1 body, kept as the parity oracle: per
-(M, N, K-block) step, one-hot expand both operands' fiber blocks to dense
-(bk, bm)/(bk, bn) tiles and apply a rank-bk MXU update to an
-output-stationary accumulator.
+``method="reference"`` — under Mosaic (the default there)
+:func:`repro.kernels.expand.expansion_gemm`; under the interpreter the PR-1
+body, kept as the parity oracle: per (M, N, K-block) step, one-hot expand
+both operands' fiber blocks to dense (bk, bm)/(bk, bn) tiles and apply a
+rank-bk MXU update to an output-stationary accumulator.
 """
 from __future__ import annotations
 
@@ -33,8 +34,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.formats.ell import EllMatrix, block_window_nnz
-from repro.kernels.expand import expand_minor
-from repro.kernels.sparse_gather import fit_block, scatter_table
+from repro.kernels.expand import expand_minor, expansion_gemm
+from repro.kernels.sparse_gather import (
+    check_sparse_lowers,
+    fit_block,
+    scatter_table,
+)
 
 #: Resident-table budget of the sparse body: A's (M, K) plus B's (N, K)
 #: f32 tables must fit alongside the operand blocks in VMEM.
@@ -44,7 +49,7 @@ OUTER_TABLE_BYTES_MAX = 8 << 20
 # ------------------------------------------------------------ reference body
 def _outer_reference_kernel(
     av_ref, ai_ref, bv_ref, bi_ref, o_ref, acc_ref,
-    *, bm: int, bn: int, k_steps: int, method: str,
+    *, bm: int, bn: int, k_steps: int,
 ):
     i, j, kk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
@@ -54,9 +59,9 @@ def _outer_reference_kernel(
 
     # Expand this K block's fibers against the (i, j) output partition.
     ea = expand_minor(ai_ref[...], av_ref[...], i * bm, bm, jnp.float32,
-                      method=method)  # (bk, bm)
+                      method="gather")  # (bk, bm)
     eb = expand_minor(bi_ref[...], bv_ref[...], j * bn, bn, jnp.float32,
-                      method=method)  # (bk, bn)
+                      method="gather")  # (bk, bn)
     # Σ_k outer(ea[k], eb[k]) == eaᵀ @ eb : one MXU rank-bk update.
     acc_ref[...] += jax.lax.dot_general(
         ea, eb, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -74,8 +79,7 @@ def _outer_reference(a, b, *, bm, bn, bk, interpret):
     out_dtype = jnp.result_type(a.vals.dtype, b.vals.dtype)
 
     kernel = functools.partial(_outer_reference_kernel, bm=bm, bn=bn,
-                               k_steps=k_steps,
-                               method="gather" if interpret else "dot")
+                               k_steps=k_steps)
     return pl.pallas_call(
         kernel,
         grid=(m // bm, n // bn, k_steps),
@@ -170,9 +174,11 @@ def spgemm_outer_pallas(
 ) -> jnp.ndarray:
     """A (K column-fibers, ids->M) × B (K row-fibers, ids->N) -> (M, N).
 
-    ``method``: ``"sparse"`` (resident scatter tables, construction ∝ nnz),
-    ``"reference"`` (PR-1 expansion oracle), or ``"auto"`` — sparse while
-    both resident tables fit the :data:`OUTER_TABLE_BYTES_MAX` VMEM budget.
+    ``method``: ``"sparse"`` (resident scatter tables, construction ∝ nnz;
+    interpreter only), ``"reference"`` (expansion body), or ``"auto"`` —
+    under the interpreter sparse while both resident tables fit the
+    :data:`OUTER_TABLE_BYTES_MAX` VMEM budget; under Mosaic always the
+    expansion body, lowered as :func:`~repro.kernels.expand.expansion_gemm`.
     Blocks auto-shrink to divide ragged shapes (``bk`` only tiles the
     reference body).
     """
@@ -184,10 +190,13 @@ def spgemm_outer_pallas(
     bn = fit_block(n, bn)
     if method == "auto":
         fits = 4 * k * (m + n) <= OUTER_TABLE_BYTES_MAX
-        method = "sparse" if fits else "reference"
+        method = "sparse" if interpret and fits else "reference"
     if method == "reference":
+        if not interpret:
+            return expansion_gemm(a, b, bm=bm, bn=bn, bk=bk)
         return _outer_reference(a, b, bm=bm, bn=bn, bk=fit_block(k, bk),
                                 interpret=interpret)
     if method == "sparse":
+        check_sparse_lowers(interpret, "spgemm_outer", "a scatter-add")
         return _outer_sparse(a, b, bm=bm, bn=bn, interpret=interpret)
     raise ValueError(f"unknown spgemm_outer method: {method!r}")

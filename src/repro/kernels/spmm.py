@@ -2,13 +2,13 @@
 
 Two bodies (DESIGN.md §7):
 
-``method="sparse"`` (default) — the sparsity-proportional body. The grid
-runs the N blocks *outermost*; at the first M step of each N block the
-kernel scatter-constructs B's dense ``(K, bn)`` column table ONCE into
-persistent VMEM scratch and amortizes it across every M block. The fiber
-chunks stream HBM→VMEM through double-buffered ``make_async_copy`` DMAs
-(fetch chunk ``c+1`` while chunk ``c`` scatters), the trip count is the
-scalar-prefetched live-chunk bound from
+``method="sparse"`` (the interpreter's default) — the sparsity-proportional
+body. The grid runs the N blocks *outermost*; at the first M step of each N
+block the kernel scatter-constructs B's dense ``(K, bn)`` column table ONCE
+into persistent VMEM scratch and amortizes it across every M block. The
+fiber chunks stream HBM→VMEM through double-buffered ``make_async_copy``
+DMAs (fetch chunk ``c+1`` while chunk ``c`` scatters), the trip count is
+the scalar-prefetched live-chunk bound from
 :func:`repro.formats.ell.block_chunk_counts` (dead chunks are never
 fetched), and an all-empty fiber block skips construction *and* the MXU
 contraction entirely (``pl.when``), writing zeros. Construction cost is
@@ -16,10 +16,11 @@ proportional to the nonzeros; the per-tile contraction is the same single
 MXU dot the expansion path pays — but paid once per tile instead of
 expansion-plus-dot.
 
-``method="reference"`` — the PR-1 one-hot/gather expansion body, kept
-verbatim as the interpret-mode parity oracle: it re-expands B's fibers to a
-dense ``(bn, K)`` tile for EVERY output tile, burning O(bn × K) per tile
-regardless of sparsity.
+``method="reference"`` — under Mosaic (the default there)
+:func:`repro.kernels.expand.expansion_gemm`; under the interpreter the PR-1
+one-hot/gather expansion body, kept verbatim as the interpret-mode parity
+oracle: it re-expands B's fibers to a dense ``(bn, K)`` tile for EVERY
+output tile, burning O(bn × K) per tile regardless of sparsity.
 """
 from __future__ import annotations
 
@@ -31,19 +32,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.formats.ell import EllMatrix, block_chunk_counts, pad_capacity
-from repro.kernels.expand import expand_minor
-from repro.kernels.sparse_gather import fit_block, scatter_table
+from repro.kernels.expand import expand_minor, expansion_gemm
+from repro.kernels.sparse_gather import (
+    check_sparse_lowers,
+    fit_block,
+    scatter_table,
+)
 
 #: Capacity-chunk width of the double-buffered fiber DMA.
 SPMM_FIBER_CHUNK = 64
 
 
 # ------------------------------------------------------------ reference body
-def _spmm_reference_kernel(a_ref, bv_ref, bi_ref, o_ref, *, k_size: int,
-                           method: str):
+def _spmm_reference_kernel(a_ref, bv_ref, bi_ref, o_ref, *, k_size: int):
     # Expand B's (bn, cap) compressed fibers into dense (bn, K) in one shot.
     eb = expand_minor(bi_ref[...], bv_ref[...], 0, k_size, jnp.float32,
-                      method=method)
+                      method="gather")
     # Single MXU contraction over K: (bm, K) · (bn, K)ᵀ — no transpose
     # materialised, dot_general contracts the shared K axis directly.
     o_ref[...] = jax.lax.dot_general(
@@ -58,8 +62,7 @@ def _spmm_reference(a, b, *, bm, bn, interpret):
     n = b.shape[1]
     cap = b.cap
     out_dtype = jnp.result_type(a.dtype, b.vals.dtype)
-    kernel = functools.partial(_spmm_reference_kernel, k_size=k,
-                               method="gather" if interpret else "dot")
+    kernel = functools.partial(_spmm_reference_kernel, k_size=k)
     return pl.pallas_call(
         kernel,
         grid=(m // bm, n // bn),
@@ -170,10 +173,13 @@ def spmm_pallas(
 ) -> jnp.ndarray:
     """Dense ``a (M, K)`` × compressed ``b`` (column fibers, ids->K) -> (M, N).
 
-    ``method``: ``"sparse"`` (proportional body), ``"reference"`` (PR-1
-    expansion oracle), or ``"auto"`` — sparse unless the fibers are so
-    dense (``cap > K/2``) that scatter construction costs more than the
-    expansion it replaces. Blocks auto-shrink to divide ragged shapes.
+    ``method``: ``"sparse"`` (proportional body, interpreter only),
+    ``"reference"`` (expansion body), or ``"auto"``. Under the interpreter
+    ``auto`` is sparse unless the fibers are so dense (``cap > K/2``) that
+    scatter construction costs more than the expansion it replaces; under
+    Mosaic it is always the expansion body, lowered as
+    :func:`~repro.kernels.expand.expansion_gemm`. Blocks auto-shrink to
+    divide ragged shapes.
     """
     assert b.major_axis == 1, "spmm expects B in U_N C_K (column fibers)"
     m, k = a.shape
@@ -182,10 +188,14 @@ def spmm_pallas(
     bm = fit_block(m, bm)
     bn = fit_block(n, bn)
     if method == "auto":
-        method = "sparse" if 2 * b.cap <= k else "reference"
+        sparse = interpret and 2 * b.cap <= k
+        method = "sparse" if sparse else "reference"
     if method == "reference":
+        if not interpret:
+            return expansion_gemm(a, b, bm=bm, bn=bn)
         return _spmm_reference(a, b, bm=bm, bn=bn, interpret=interpret)
     if method == "sparse":
+        check_sparse_lowers(interpret, "spmm", "a scatter-add")
         fc = min(SPMM_FIBER_CHUNK, b.cap)
         return _spmm_sparse(a, b, bm=bm, bn=bn, fc=fc, interpret=interpret)
     raise ValueError(f"unknown spmm method: {method!r}")

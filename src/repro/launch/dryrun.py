@@ -29,7 +29,6 @@ from repro.launch.mesh import (
     axis_sizes,
     batch_axes,
     make_production_mesh,
-    set_mesh,
 )
 from repro.models import build
 from repro.models.config import SHAPES_BY_NAME, ShapeSpec
@@ -196,11 +195,9 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     t0 = time.time()
     try:
         fn, structs, in_sh, donate = lower_cell(arch, shape_name, mesh)
-        # `with mesh:` is the legacy context (spec template); set_mesh
-        # additionally publishes the abstract mesh that shard_map-based
-        # context parallelism resolves at trace time (compat shim — the
-        # entry point moved across JAX releases).
-        with mesh, set_mesh(mesh):
+        # set_mesh publishes the abstract mesh that shard_map-based
+        # context parallelism resolves at trace time.
+        with jax.set_mesh(mesh):
             jitted = jax.jit(fn, in_shardings=in_sh, donate_argnums=donate)
             lowered = jitted.lower(*structs)
             t_lower = time.time() - t0

@@ -1189,6 +1189,10 @@ class FleetServer:
         env = dict(os.environ)
         env["PYTHONPATH"] = src_root + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+        # The worker serves telemetry-only (execute=False): it needs no
+        # accelerator, and on a TPU host the parent may already hold the
+        # chip, which a second process cannot open.
+        env["JAX_PLATFORMS"] = "cpu"
 
         records: List[FleetRequestRecord] = []
         per_replica: List[ReplicaReport] = []

@@ -6,79 +6,24 @@ Multi-pod: 2×16×16 = 512 chips; the 'pod' axis is DCN-connected pure DP.
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Dict, Tuple
 
 import jax
-
-
-def set_mesh(mesh: "jax.sharding.Mesh"):
-    """Compat shim over JAX's moving ambient-mesh API.
-
-    The context-setting entry point has migrated across releases
-    (``jax.sharding.set_mesh`` -> ``jax.set_mesh``, with
-    ``jax.sharding.use_mesh`` in between); older releases have none and the
-    legacy ``with mesh:`` context alone sets the ambient mesh. Returns a
-    context manager; use as ``with mesh, set_mesh(mesh):`` so both the
-    legacy and the new ambient-mesh state are active wherever supported.
-    Never touch ``jax.sharding.set_mesh`` directly — route through here.
-    """
-    for getter in (
-        lambda: jax.set_mesh,                   # jax >= 0.6
-        lambda: jax.sharding.set_mesh,          # transitional releases
-        lambda: jax.sharding.use_mesh,          # 0.5.x experimental name
-    ):
-        try:
-            fn = getter()
-        except AttributeError:
-            continue
-        return fn(mesh)
-    # Old JAX (e.g. 0.4.x): no ambient-mesh setter; `with mesh:` suffices.
-    return contextlib.nullcontext(mesh)
-
-
-def get_abstract_mesh():
-    """Compat shim for reading the ambient mesh inside traced code.
-
-    New JAX exposes ``jax.sharding.get_abstract_mesh``; on older releases
-    the ``with mesh:`` context stores the physical mesh in thread
-    resources, which serves the same purpose for ``shard_map`` (it accepts
-    ``Mesh | AbstractMesh``) and has the same ``.shape`` mapping.
-    """
-    try:
-        return jax.sharding.get_abstract_mesh()
-    except AttributeError:
-        pass
-    from jax._src import mesh as mesh_lib
-
-    return mesh_lib.thread_resources.env.physical_mesh
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Compat shim over the moving shard_map entry point.
-
-    ``jax.shard_map(..., check_vma=)`` on new JAX;
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)`` (same flag,
-    earlier name) on older releases.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> jax.sharding.Mesh:
-    """Arbitrary mesh (tests / elastic rescale)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (tests / elastic rescale). Axes are ``Auto``: the
+    callers place data with ``with_sharding_constraint`` and
+    ``NamedSharding``, which ``Explicit`` axes (the ``jax.make_mesh``
+    default) reject."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def axis_sizes(mesh: jax.sharding.Mesh) -> Dict[str, int]:

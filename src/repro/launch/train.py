@@ -17,7 +17,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import all_archs, get_config, get_reduced
 from repro.data import DataConfig, TokenDataset
-from repro.launch.mesh import axis_sizes, batch_axes, make_mesh, set_mesh
+from repro.launch.mesh import axis_sizes, batch_axes, make_mesh
 from repro.models import build
 from repro.models.layers import Axes
 from repro.optim import AdamWConfig, Compressor
@@ -76,7 +76,7 @@ def main() -> None:
     baxes = batch_axes(mesh)
     batch_sh = NamedSharding(mesh, P(baxes, None))
 
-    with mesh, set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = jax.jit(make_train_step(model, axes, tcfg),
                        in_shardings=(state_sh,
                                      {"tokens": batch_sh, "labels": batch_sh}),

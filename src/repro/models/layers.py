@@ -360,10 +360,8 @@ def _decode_attend(qg, k_cache, v_cache, pos, window, dh, k_positions):
 def _cp_decode_attend(qg, k_cache, v_cache, pos, window, dh, axes: Axes):
     """Context-parallel decode: cache sequence dim sharded over axes.seq;
     exact softmax via (max, sum) psum flash-combine."""
-    from repro.launch.mesh import get_abstract_mesh, shard_map
-
     seq_ax = axes.seq
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     n_shards = mesh.shape[seq_ax]
     s_shard = k_cache.shape[1] // n_shards
     scale = 1.0 / math.sqrt(dh)
@@ -388,7 +386,7 @@ def _cp_decode_attend(qg, k_cache, v_cache, pos, window, dh, axes: Axes):
         return o / jnp.maximum(l, 1e-30)[..., None]
 
     spec_cache = P(None, seq_ax, None, None)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), spec_cache, spec_cache, P()),

@@ -13,6 +13,7 @@ def run_example(args):
 
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"   # tests leave it off
     out = subprocess.run([sys.executable] + args, capture_output=True,
                          text=True, timeout=540, env=env, cwd="/root/repo")
     assert out.returncode == 0, (out.stdout[-1500:], out.stderr[-1500:])

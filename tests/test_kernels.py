@@ -227,6 +227,35 @@ def test_sparse_matches_reference_body(dtype, density):
         np.testing.assert_allclose(got, want, err_msg=name, **tol(dtype))
 
 
+# The expansion body as Mosaic runs it (kernels/expand.expansion_gemm: each
+# compressed operand expanded once, then the gemm kernel), driven through
+# the interpreter: every class's operand formats, ragged shapes, a fully
+# dense operand (cap == minor size) and an all-zero one.
+EXPANSION_FORMATS = {           # class -> (A major axis, B major axis)
+    "spmm": (None, 1), "spmm_mirror": (0, None), "inner": (0, 1),
+    "outer": (1, 0), "gustavson": (1, 1),
+}
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("cls", sorted(EXPANSION_FORMATS))
+def test_mosaic_expansion_body_matches_dense(cls, dtype):
+    from repro.kernels.expand import expansion_gemm
+
+    rng = np.random.default_rng(13)
+    for (m, k, n), da, db in [((100, 300, 140), 0.05, 1.0),
+                              ((130, 70, 260), 1.0, 0.0)]:
+        a, b = make_operands(rng, m, k, n, da, db, dtype)
+        want = np.asarray(a, np.float32) @ np.asarray(b, np.float32)
+        ops_ = [x if ax is None else F.dense_to_ell(
+            x, ax, F.required_capacity(x, ax), strict=True)
+            for x, ax in zip((a, b), EXPANSION_FORMATS[cls])]
+        got = expansion_gemm(*ops_, bm=128, bn=128, bk=128, interpret=True)
+        assert got.shape == (m, n)
+        np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                                   **tol(dtype))
+
+
 def test_sparse_kernels_fiber_at_exact_capacity():
     """A fiber holding exactly ``cap`` nonzeros fills every capacity chunk:
     the live-chunk bound equals the chunk count and nothing is skipped."""

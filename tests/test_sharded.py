@@ -22,7 +22,7 @@ from repro.configs import get_reduced
 from repro.models import build
 from repro.models.layers import Axes
 from repro.sharding import param_pspecs, named_shardings, cache_pspecs
-from repro.launch.mesh import make_mesh, axis_sizes, set_mesh
+from repro.launch.mesh import make_mesh, axis_sizes
 """
 
 
@@ -61,7 +61,7 @@ state_specs = {"params": pspecs,
                "error": jax.tree_util.tree_map(lambda _: P(), state["error"])}
 axes = Axes(batch=("data",), model="model", fsdp="data",
             sizes=tuple(axis_sizes(mesh).items()))
-with mesh, set_mesh(mesh):
+with jax.set_mesh(mesh):
     step8 = jax.jit(make_train_step(model, axes, tcfg),
                     in_shardings=(named_shardings(state_specs, mesh),
                                   named_shardings({"tokens": P("data", None),
@@ -105,7 +105,7 @@ axes = Axes(batch=(), model="model", fsdp="data", seq="data",
             sizes=tuple(axis_sizes(mesh).items()))
 cspecs = cache_pspecs(cache, (), axis_sizes(mesh), seq_shard=True)
 from repro.sharding import named_shardings
-with mesh, set_mesh(mesh):
+with jax.set_mesh(mesh):
     stepc = jax.jit(make_decode_step(model, axes),
                     in_shardings=(None, named_shardings(cspecs, mesh),
                                   None, None))
