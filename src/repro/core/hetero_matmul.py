@@ -18,12 +18,14 @@ partitions and repeated calls (DESIGN.md §2).
 """
 from __future__ import annotations
 
+import contextlib
 from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs as _obs
 from repro.core import costmodel as cm
 from repro.core.scheduler import (
     KernelSchedule,
@@ -35,6 +37,11 @@ from repro.core.workloads import Workload
 from repro.formats.ell import bucket_capacity, dense_to_ell
 from repro.formats.taxonomy import DataflowClass
 from repro.kernels import ops
+
+# Dense operands handed to the ELL conversion, counted on the host once per
+# conversion dispatched (both executors, :func:`count_conversions`).
+_MET_ELL_CONVERSIONS = _obs.METRICS.counter("executor.ell.conversions")
+_MET_ELL_BYTES = _obs.METRICS.counter("executor.ell.dense_bytes")
 
 
 def _compressed_operands(cls: DataflowClass, mirror: bool):
@@ -59,25 +66,31 @@ def _fiber_nnz_max(x: jnp.ndarray, major_axis: int) -> jnp.ndarray:
     return jnp.max(jnp.sum(work != 0, axis=-1))
 
 
-def _prep_operands(cls: DataflowClass, a, b, mirror: bool, caps):
+def _prep_operands(cls: DataflowClass, a, b, mirror: bool, caps,
+                   scope=None):
     """Device slices -> REQUIRED_FORMATS[cls] operands.
 
     ``caps`` are the bucketed static capacities for each compressed operand,
-    in :func:`_compressed_operands` order.
+    in :func:`_compressed_operands` order. ``scope(operand)``, when given,
+    is a context entered around the conversion of that operand ("a"/"b").
     """
-    if cls == DataflowClass.GEMM:
-        return a, b
-    if cls == DataflowClass.SPMM:
-        if mirror:
-            return dense_to_ell(a, 0, caps[0]), b
-        return a, dense_to_ell(b, 1, caps[0])
-    if cls == DataflowClass.SPGEMM_INNER:
-        return dense_to_ell(a, 0, caps[0]), dense_to_ell(b, 1, caps[1])
-    if cls == DataflowClass.SPGEMM_OUTER:
-        return dense_to_ell(a, 1, caps[0]), dense_to_ell(b, 0, caps[1])
-    if cls == DataflowClass.SPGEMM_GUSTAVSON:
-        return dense_to_ell(a, 1, caps[0]), dense_to_ell(b, 1, caps[1])
-    raise ValueError(cls)
+    x = {"a": a, "b": b}
+    for (operand, ax), cap in zip(_compressed_operands(cls, mirror), caps):
+        with scope(operand) if scope else contextlib.nullcontext():
+            x[operand] = dense_to_ell(x[operand], ax, cap)
+    return x["a"], x["b"]
+
+
+def count_conversions(p, a_dtype, b_dtype) -> None:
+    """Count the ELL conversions partition ``p`` makes, and the dense bytes
+    handed to them (its region's slice of each compressed operand), into
+    ``executor.ell.conversions`` / ``executor.ell.dense_bytes``."""
+    r = p.region
+    size = {"a": (r.m1 - r.m0) * (r.k1 - r.k0) * np.dtype(a_dtype).itemsize,
+            "b": (r.k1 - r.k0) * (r.n1 - r.n0) * np.dtype(b_dtype).itemsize}
+    for operand, _ in _compressed_operands(p.cls, p.mirror):
+        _MET_ELL_CONVERSIONS.inc()
+        _MET_ELL_BYTES.inc(size[operand])
 
 
 def _dispatch_partition(cls: DataflowClass, a, b, mirror: bool,
@@ -117,21 +130,24 @@ def prepare_partitions(jobs):
     """
     # Pass 1 (device): slice operands, queue capacity-need scalars.
     sliced, needs = [], []
-    for a_d, b_d, parts in jobs:
-        rows = []
-        for p in parts:
-            r = p.region
-            sa = a_d[r.m0:r.m1, r.k0:r.k1]
-            sb = b_d[r.k0:r.k1, r.n0:r.n1]
-            refs = []
-            for operand, ax in _compressed_operands(p.cls, p.mirror):
-                x = sa if operand == "a" else sb
-                refs.append((x, ax, len(needs)))
-                needs.append(_fiber_nnz_max(x, ax))
-            rows.append((p, sa, sb, refs))
-        sliced.append(rows)
+    with _obs.TRACE.span("exec.slice", tid="executor", cat="executor"):
+        for a_d, b_d, parts in jobs:
+            rows = []
+            for p in parts:
+                r = p.region
+                sa = a_d[r.m0:r.m1, r.k0:r.k1]
+                sb = b_d[r.k0:r.k1, r.n0:r.n1]
+                refs = []
+                for operand, ax in _compressed_operands(p.cls, p.mirror):
+                    x = sa if operand == "a" else sb
+                    refs.append((x, ax, len(needs)))
+                    needs.append(_fiber_nnz_max(x, ax))
+                rows.append((p, sa, sb, refs))
+            sliced.append(rows)
     # One host sync for every static capacity in the batch.
-    need_vals = jax.device_get(needs) if needs else []
+    with _obs.TRACE.span("exec.capacity_sync", tid="executor",
+                         cat="executor"):
+        need_vals = jax.device_get(needs) if needs else []
 
     prepared = []
     for rows in sliced:
@@ -196,23 +212,32 @@ def execute_schedule(a, b, schedule: KernelSchedule,
     # Pass 2 (device): convert at bucketed caps, dispatch, group by tile.
     tiles: dict = {}
     for p, sa, sb, caps in prepare_partitions([(a_d, b_d, parts)])[0]:
-        pa, pb = _prep_operands(p.cls, sa, sb, p.mirror, caps)
+        cls = p.cls.value
+        count_conversions(p, sa.dtype, sb.dtype)
+        pa, pb = _prep_operands(
+            p.cls, sa, sb, p.mirror, caps,
+            scope=lambda operand: _obs.TRACE.span(
+                "exec.ell", tid="executor", cat="executor", cls=cls,
+                operand=operand))
         if cost_sink is not None:
             cost_sink.append(ops.op_cost(p.cls, pa, pb, bm=block, bn=block,
                                          mirror=p.mirror))
-        partial = _dispatch_partition(p.cls, pa, pb, p.mirror,
-                                      interpret, block)
+        with _obs.TRACE.span("exec.kernel", tid="executor", cat="executor",
+                             cls=cls):
+            partial = _dispatch_partition(p.cls, pa, pb, p.mirror,
+                                          interpret, block)
         r = p.region
         tiles.setdefault((r.m0, r.m1, r.n0, r.n1), []).append(partial)
 
     # Merge: K-split partials for the same output tile sum first, then each
     # tile lands with one scatter-add.
-    out = jnp.zeros((m, n), out_dtype)
-    for (m0, m1, n0, n1), partials in tiles.items():
-        acc = partials[0].astype(out_dtype)
-        for q in partials[1:]:
-            acc = acc + q.astype(out_dtype)
-        out = out.at[m0:m1, n0:n1].add(acc)
+    with _obs.TRACE.span("exec.merge", tid="executor", cat="executor"):
+        out = jnp.zeros((m, n), out_dtype)
+        for (m0, m1, n0, n1), partials in tiles.items():
+            acc = partials[0].astype(out_dtype)
+            for q in partials[1:]:
+                acc = acc + q.astype(out_dtype)
+            out = out.at[m0:m1, n0:n1].add(acc)
     return out
 
 
@@ -385,10 +410,11 @@ def execute_assignments(
     for asg, a_d, b_d in jobs:
         parts = tuple(pp.partition for pp in asg.placed)
         ks = KernelSchedule(asg.workload, config, parts, asg.report)
-        outs[asg.task_index] = execute_schedule(jnp.asarray(a_d),
-                                                jnp.asarray(b_d), ks,
-                                                interpret=interpret,
-                                                block=block)
+        with _obs.TRACE.span("exec.request", tid="executor", cat="executor",
+                        task=asg.task_index):
+            outs[asg.task_index] = execute_schedule(
+                jnp.asarray(a_d), jnp.asarray(b_d), ks,
+                interpret=interpret, block=block)
     return outs
 
 

@@ -86,6 +86,7 @@ from repro.core.hetero_matmul import (
     _dispatch_partition,
     _prep_operands,
     cluster_submeshes,
+    count_conversions,
     prepare_partitions,
 )
 from repro.core.scheduler import KernelSchedule
@@ -319,7 +320,9 @@ def _build_program(mesh, axis, per_device, out_shapes, operand_struct,
                     r = p.region
                     sa = a_list[job_idx][r.m0:r.m1, r.k0:r.k1]
                     sb = b_list[job_idx][r.k0:r.k1, r.n0:r.n1]
-                    pa, pb = _prep_operands(p.cls, sa, sb, p.mirror, caps)
+                    with jax.named_scope("exec.ell"):
+                        pa, pb = _prep_operands(p.cls, sa, sb, p.mirror,
+                                                caps)
                     partial = _dispatch_partition(p.cls, pa, pb, p.mirror,
                                                   interpret, block)
                     dtype = out_shapes[job_idx][1]
@@ -368,7 +371,9 @@ def _build_packed_program(mesh, axis, meta, out_shapes, payload_struct,
                     bn = r.n1 - r.n0
                     sa = rows[ia][off_a:off_a + am * ak].reshape(am, ak)
                     sb = rows[ib][off_b:off_b + ak * bn].reshape(ak, bn)
-                    pa, pb = _prep_operands(p.cls, sa, sb, p.mirror, caps)
+                    with jax.named_scope("exec.ell"):
+                        pa, pb = _prep_operands(p.cls, sa, sb, p.mirror,
+                                                caps)
                     partial = _dispatch_partition(p.cls, pa, pb, p.mirror,
                                                   interpret, block)
                     dtype = out_shapes[job_idx][1]
@@ -536,6 +541,10 @@ def _dispatch_batch(batch_id, jobs, config, mesh, axis, interpret, block,
         fn = _build_packed_program(mesh, axis, meta, out_shapes,
                                    payload_struct, interpret, block,
                                    measure)
+        for assigned in meta:
+            for _, p, _, ia, _, ib, _ in assigned:
+                count_conversions(p, payload_struct[ia][0],
+                                  payload_struct[ib][0])
         sharding = NamedSharding(mesh, P(axis))
         dev_payloads = tuple(jax.device_put(buf, sharding)
                              for buf in payloads)
@@ -581,6 +590,9 @@ def _dispatch_batch(batch_id, jobs, config, mesh, axis, interpret, block,
         tuple((a.shape, a.dtype, b.shape, b.dtype)
               for a, b in zip(a_ops, b_ops)),
         interpret, block)
+    for assigned in per_device:
+        for job_idx, p, _ in assigned:
+            count_conversions(p, a_ops[job_idx].dtype, b_ops[job_idx].dtype)
     dispatch_s = time.perf_counter() - origin
     with jax.set_mesh(mesh):
         outs = fn(a_ops, b_ops)

@@ -218,6 +218,7 @@ def expand_table_pallas(e, *, rows: int, cols: int,
         out_specs=pl.BlockSpec((bw, bf), lambda w, f, c: (w, f)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), jnp.float32),
         interpret=interpret,
+        name="expand_table",
     )(ids, vals)
 
 

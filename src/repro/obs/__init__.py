@@ -5,7 +5,7 @@ Two process singletons:
 * :data:`TRACE` — ring-buffer tracer exporting Chrome trace-event JSON
   (Perfetto-loadable timelines: scheduler placements, serve requests,
   executor batches, measured submesh windows).
-* :data:`METRICS` — named counters/gauges/histograms with
+* :data:`METRICS` — named counters/gauges with
   ``snapshot()`` / ``reset()`` / JSON export.
 
 Both are off-by-default / free-when-idle: flip :func:`enable` to start
@@ -35,7 +35,6 @@ from .trace import (  # noqa: F401
 from .metrics import (  # noqa: F401
     Counter,
     Gauge,
-    Histogram,
     METRICS,
     MetricsRegistry,
 )
@@ -65,6 +64,6 @@ __all__ = [
     "ENABLED", "PID_HOST", "PID_MEASURED", "PID_VIRTUAL",
     "TRACE", "Tracer", "disable", "enable", "enabled",
     "write_chrome_trace",
-    "Counter", "Gauge", "Histogram", "METRICS", "MetricsRegistry",
+    "Counter", "Gauge", "METRICS", "MetricsRegistry",
     "log", "set_quiet",
 ]

@@ -23,6 +23,14 @@ never share a row:
   (``sharded_exec.BatchTimeline`` re-emitted, §6 measured semantics).
 * ``PID_HOST`` — host/driver wall-clock spans (dispatch/retire, DSE).
 
+**The profiler's clock.** :meth:`Tracer.span` also opens a
+``jax.profiler.TraceAnnotation`` of the same name and arguments whenever
+a JAX profiler session is live, whether or not :data:`ENABLED` is set.
+The span then lands on the profiler's host plane, on the clock of the
+device trace, nested in the spans that enclose it on the same thread, so
+a device operation can be charged to the program stage that launched it
+(``bench/trace_reduce.py``). With no session live this costs one check.
+
 Wall-clock timestamps are relative to the tracer's epoch
 (``perf_counter`` at construction / :meth:`Tracer.reset`);
 :meth:`Tracer.ts_from_perf` maps an absolute ``perf_counter`` stamp onto
@@ -33,14 +41,15 @@ the shared timebase.
 **Disabled-path guarantee (§8).** Tracing is off by default. The
 module-level :data:`ENABLED` flag is checked before *any* allocation:
 every recording method early-returns and :meth:`Tracer.span` hands back a
-shared no-op context manager, so instrumented hot loops pay one global
-load + branch per site (gated in ``tests/test_obs.py`` and the
-``obs/overhead`` bench row). With tracing off, instrumented code paths
-are bit-identical to uninstrumented ones — recording never influences a
-decision.
+shared no-op context manager (unless a profiler session is live), so
+instrumented hot loops pay one global load + branch per site (gated in
+``tests/test_obs.py`` and the ``obs/overhead`` bench row). With tracing
+off, instrumented code paths are bit-identical to uninstrumented ones —
+recording never influences a decision.
 
-Stdlib only — this module must stay importable from every layer
-(kernels, scheduler, serving, benchmarks) without dragging jax/numpy in.
+Stdlib only at import — this module must stay importable from every
+layer (kernels, scheduler, serving, benchmarks) without dragging jax/numpy
+in; the first :meth:`Tracer.span` imports ``jax.profiler``.
 """
 from __future__ import annotations
 
@@ -99,21 +108,38 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+#: ``jax.profiler.TraceAnnotation``, imported by the first span.
+_ANNOTATION = None
+
+
+def _annotation():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
 
 class _Span:
-    """Live wall-clock span; records a ``ph:"X"`` event on exit."""
+    """Live wall-clock span; records a ``ph:"X"`` event on exit, inside
+    the profiler annotation ``annotation`` when one is given."""
 
-    __slots__ = ("_tracer", "name", "cat", "pid", "tid", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "pid", "tid", "args",
+                 "_annotation", "_t0")
 
-    def __init__(self, tracer, name, cat, pid, tid, args):
+    def __init__(self, tracer, name, cat, pid, tid, args, annotation):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.pid = pid
         self.tid = tid
         self.args = args
+        self._annotation = annotation
 
     def __enter__(self):
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -123,6 +149,8 @@ class _Span:
         tr.complete(self.name, tr.ts_from_perf(self._t0),
                     (t1 - self._t0) * 1e6, pid=self.pid, tid=self.tid,
                     cat=self.cat, **self.args)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         return False
 
 
@@ -208,11 +236,16 @@ class Tracer:
 
     def span(self, name: str, *, pid: int = PID_HOST, tid: Tid = 0,
              cat: str = "", **args):
-        """Wall-clock span context manager; no-op singleton when
-        disabled (zero allocation on the disabled path)."""
+        """Wall-clock span context manager, also a profiler
+        ``TraceAnnotation(name, **args)`` while a profiler session is
+        live; the no-op singleton when disabled and no session is live
+        (zero allocation on that path)."""
+        annotation = _annotation()
+        live = annotation.is_enabled()
         if not ENABLED:
-            return _NULL_SPAN
-        return _Span(self, name, cat, pid, tid, args)
+            return annotation(name, **args) if live else _NULL_SPAN
+        return _Span(self, name, cat, pid, tid, args,
+                     annotation(name, **args) if live else None)
 
     # ---------------------------------------------------------- metadata
     def name_thread(self, pid: int, tid: int, name: str) -> None:

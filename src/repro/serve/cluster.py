@@ -590,34 +590,37 @@ class ClusterServer:
             raise ValueError(
                 "measure=True requires shard_operands=True (the replicated "
                 "program has no span-granular fences)")
-        requests = sorted(self._pending,
-                          key=lambda r: (r.arrival_cycles, r.request_id))
-        self._pending = []
-        ids = [r.request_id for r in requests]
-        if len(set(ids)) != len(ids):
-            raise ValueError("duplicate request_id in trace")
+        with _obs.TRACE.span("serve.schedule", tid="serve", cat="serve",
+                             requests=len(self._pending)):
+            requests = sorted(self._pending,
+                              key=lambda r: (r.arrival_cycles, r.request_id))
+            self._pending = []
+            ids = [r.request_id for r in requests]
+            if len(set(ids)) != len(ids):
+                raise ValueError("duplicate request_id in trace")
 
-        engine = OnlineScheduler(self.config, self.policy)
-        admitted: Dict[int, Tuple[Request, float, int]] = {}
-        i = 0
-        batch_id = 0
-        while i < len(requests):
-            open_t = requests[i].arrival_cycles
-            close_t = open_t + self.batch_window_cycles
-            batch = [r for r in requests[i:] if r.arrival_cycles <= close_t]
-            i += len(batch)
-            admit = close_t if self.batch_window_cycles > 0.0 else open_t
-            engine.advance(until=admit)
-            if self.max_queue_depth is not None:
-                self._defer_for_depth(engine)
-            admit = max(admit, engine.now)
-            for r in batch:
-                idx = engine.offer(r.workload, arrival=admit)
-                admitted[idx] = (r, admit, batch_id)
-            _trace_admission(self, open_t, admit, batch_id, len(batch))
-            batch_id += 1
-        engine.drain()
-        schedule = engine.finish()
+            engine = OnlineScheduler(self.config, self.policy)
+            admitted: Dict[int, Tuple[Request, float, int]] = {}
+            i = 0
+            batch_id = 0
+            while i < len(requests):
+                open_t = requests[i].arrival_cycles
+                close_t = open_t + self.batch_window_cycles
+                batch = [r for r in requests[i:]
+                         if r.arrival_cycles <= close_t]
+                i += len(batch)
+                admit = close_t if self.batch_window_cycles > 0.0 else open_t
+                engine.advance(until=admit)
+                if self.max_queue_depth is not None:
+                    self._defer_for_depth(engine)
+                admit = max(admit, engine.now)
+                for r in batch:
+                    idx = engine.offer(r.workload, arrival=admit)
+                    admitted[idx] = (r, admit, batch_id)
+                _trace_admission(self, open_t, admit, batch_id, len(batch))
+                batch_id += 1
+            engine.drain()
+            schedule = engine.finish()
 
         by_index = {a.task_index: a for a in schedule.assignments}
         outputs: Dict[int, object] = {}
@@ -635,10 +638,13 @@ class ClusterServer:
                 else:
                     ops_by_index[idx] = request_operands(r,
                                                          max_elems=max_elems)
+            execute_span = _obs.TRACE.span("serve.execute", tid="serve",
+                                           cat="serve", batches=batch_id)
             if mesh is None:
-                outputs = execute_assignments(
-                    schedule.assignments, ops_by_index, self.config,
-                    interpret=interpret, block=block)
+                with execute_span:
+                    outputs = execute_assignments(
+                        schedule.assignments, ops_by_index, self.config,
+                        interpret=interpret, block=block)
             else:
                 # Sharded path: one multi-cluster shard_map program per
                 # admitted batch, pipelined in admission order (at most
@@ -650,14 +656,15 @@ class ClusterServer:
                 for idx, (_, _, bid) in admitted.items():
                     per_batch.setdefault(bid, []).append(by_index[idx])
                 timelines = []
-                outputs = execute_assignment_batches(
-                    [per_batch[bid] for bid in sorted(per_batch)],
-                    ops_by_index, self.config,
-                    interpret=interpret, block=block,
-                    mesh=mesh, mesh_axis=mesh_axis,
-                    pipeline_depth=pipeline_depth,
-                    shard_operands=shard_operands,
-                    measure=measure, timeline_sink=timelines)
+                with execute_span:
+                    outputs = execute_assignment_batches(
+                        [per_batch[bid] for bid in sorted(per_batch)],
+                        ops_by_index, self.config,
+                        interpret=interpret, block=block,
+                        mesh=mesh, mesh_axis=mesh_axis,
+                        pipeline_depth=pipeline_depth,
+                        shard_operands=shard_operands,
+                        measure=measure, timeline_sink=timelines)
 
         results = []
         for idx in sorted(admitted):

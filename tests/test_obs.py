@@ -115,27 +115,60 @@ def test_tracer_ring_buffer_caps_and_counts_drops():
     assert tr.events() == [] and tr.dropped == 0
 
 
+@pytest.mark.parametrize("enabled", [False, True])
+def test_span_lands_nested_in_the_profiler_trace(tmp_path, enabled):
+    """With a profiler session live, ``Tracer.span`` is also a
+    ``TraceAnnotation``: named, nested in its parent on one thread, its
+    arguments as stats. The ring buffer records only while enabled, and
+    once the session ends a disabled span is the no-op singleton again."""
+    import glob
+
+    import jax
+
+    tr = obs.Tracer()
+    obs.enable(enabled)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("serve.schedule", requests=2):
+            with tr.span("exec.ell", cls="spmm", operand="b"):
+                time.sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+        obs.disable()
+    assert [e["name"] for e in tr.events()] == (
+        ["exec.ell", "serve.schedule"] if enabled else [])
+    assert tr.span("w") is tr.span("w2")
+
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in ("serve.schedule", "exec.ell"):
+                    found[e.name] = (line.name, e.start_ns, e.end_ns,
+                                     dict(e.stats))
+    outer, inner = found["serve.schedule"], found["exec.ell"]
+    assert outer[0] == inner[0]                      # one thread
+    assert outer[1] <= inner[1] and inner[2] <= outer[2]
+    assert inner[2] - inner[1] >= 1e6                # slept 1 ms
+    assert inner[3] == {"cls": "spmm", "operand": "b"}
+    assert outer[3] == {"requests": 2}
+
+
 # ----------------------------------------------------------------- metrics
 def test_metrics_registry_snapshot_reset_and_callbacks():
     reg = obs.MetricsRegistry()
     c = reg.counter("c")
     g = reg.gauge("g")
-    h = reg.histogram("h")
     c.inc()
     c.inc(2.5)
     g.set(7)
     g.dec(3)
-    for v in range(1, 101):
-        h.observe(float(v))
     reg.register_callback("ext", lambda: {"k": 42})
     reg.register_callback("broken", lambda: 1 / 0)
     snap = reg.snapshot()
     assert snap["counters"]["c"] == 3.5
     assert snap["gauges"]["g"] == 4.0
-    hs = snap["histograms"]["h"]
-    assert hs["count"] == 100 and hs["min"] == 1.0 and hs["max"] == 100.0
-    assert hs["p50"] == pytest.approx(50.5)
-    assert hs["p99"] == pytest.approx(99.01)
     assert snap["derived"]["ext"] == {"k": 42}
     assert "error" in snap["derived"]["broken"]
     assert reg.counter("c") is c  # get-or-create returns the live object
@@ -143,7 +176,6 @@ def test_metrics_registry_snapshot_reset_and_callbacks():
     snap2 = reg.snapshot()
     assert snap2["counters"]["c"] == 0.0
     assert snap2["gauges"]["g"] == 0.0
-    assert snap2["histograms"]["h"]["count"] == 0
     json.dumps(snap)  # snapshot is JSON-serialisable as-is
 
 
@@ -376,3 +408,53 @@ def test_disabled_overhead_within_factor_of_stubbed_baseline():
     assert off <= 3.0 * noop + 500.0, (
         f"tracing-disabled drain {off:.0f}us vs stubbed baseline "
         f"{noop:.0f}us")
+
+
+# ------------------------------------------------- ELL conversion counters
+def _ell_batch():
+    """One 64x48x40 task split by hand over three partitions: an SpMM
+    compressing B on the first 16 columns, and the other 24 columns
+    K-split between an inner-product SpGEMM (A and B compressed) and a
+    mirrored SpMM (A compressed)."""
+    from repro.core.scheduler import (
+        Partition, PlacedPartition, Region, TaskAssignment)
+
+    w = Workload("hand", "test", 64, 48, 40, 0.2, 0.3)
+    parts = (
+        Partition(Region(0, 64, 0, 48, 0, 16), D.SPMM, 0),
+        Partition(Region(0, 64, 0, 24, 16, 40), D.SPGEMM_INNER, 0),
+        Partition(Region(0, 64, 24, 48, 16, 40), D.SPMM, 0, mirror=True),
+    )
+    asg = TaskAssignment(w, 0, D.SPMM, False, 0.0, 1.0, None, task_index=0,
+                         placed=tuple(PlacedPartition(p, 0.0, 1.0)
+                                      for p in parts))
+    rng = np.random.default_rng(3)
+    a = (rng.random((64, 48)) < 0.2) * rng.standard_normal((64, 48))
+    b = (rng.random((48, 40)) < 0.3) * rng.standard_normal((48, 40))
+    return asg, a.astype(np.float32), b.astype(np.float32)
+
+
+@pytest.mark.parametrize("path", ["sequential", "sharded_packed",
+                                  "sharded_replicated"])
+def test_ell_counters_match_a_hand_count(path):
+    """``executor.ell.conversions`` / ``executor.ell.dense_bytes`` count
+    each conversion dispatched and the dense f32 slice it is handed: B's
+    48x16, A's 64x24 and B's 24x24, A's 64x24 — 4 conversions, 17,664
+    bytes — on the sequential path and on the sharded one (one device)."""
+    from repro.core.hetero_matmul import execute_assignments
+    from repro.launch.mesh import make_mesh
+
+    asg, a, b = _ell_batch()
+    kw = {}
+    if path != "sequential":
+        kw = dict(mesh=make_mesh((1,), ("model",)),
+                  shard_operands=path == "sharded_packed")
+    conv = obs.METRICS.counter("executor.ell.conversions")
+    dense = obs.METRICS.counter("executor.ell.dense_bytes")
+    c0, b0 = conv.value, dense.value
+    out = execute_assignments([asg], {0: (a, b)},
+                              cm.homogeneous_hybrid(math.inf),
+                              interpret=True, block=16, **kw)[0]
+    assert conv.value - c0 == 4
+    assert dense.value - b0 == 4 * (48 * 16 + 64 * 24 + 24 * 24 + 64 * 24)
+    np.testing.assert_allclose(np.asarray(out), a @ b, rtol=1e-4, atol=1e-4)
